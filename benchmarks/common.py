@@ -4,11 +4,12 @@ Reporting protocol follows the paper (§VI): per size we run `reps`
 timed calls and report the HARMONIC mean of flops/s (equivalently the
 arithmetic mean of execution times), with errors omitted below 1%.
 
-This container is CPU-only, so wall-clock numbers are RELATIVE (they
-rank implementations and show scaling); absolute TPU-v5e projections
-come from the roofline model over MXU pass counts (`tpu_projection`),
-and — for the full framework cells — from compiled-HLO analysis in
-benchmarks/roofline.py. Both are labeled explicitly in the output.
+Wall-clock numbers are readings of whatever backend JAX runs on. On the
+CPU (Pallas in interpret mode) they only rank implementations and show
+scaling; they are not device metrics. `tpu_projection` and
+benchmarks/roofline.py give MODELLED TPU-v5e numbers from MXU pass
+counts and compiled-HLO analysis, labeled as such. A measured chip
+number comes only from a run on the chip (see `chip_smoke.py`).
 """
 
 from __future__ import annotations

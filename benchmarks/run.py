@@ -140,6 +140,8 @@ def write_moe_json(matrix: dict) -> str:
 
 
 def main() -> None:
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="smaller sweeps (CI-sized)")

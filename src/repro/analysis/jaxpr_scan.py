@@ -135,11 +135,8 @@ def _pallas_site(eqn) -> PallasSite:
     grid = tuple(getattr(gm, "grid", ()) or ())
     mappings = []
     for bm in getattr(gm, "block_mappings", ()) or ():
-        index_map = getattr(bm, "index_map_jaxpr", None)
-        array_sd = getattr(bm, "array_shape_dtype", None)
-        mappings.append((tuple(bm.block_shape),
-                         tuple(getattr(array_sd, "shape", ()) or ()),
-                         index_map))
+        mappings.append((tuple(bm.block_shape), tuple(bm.array_aval.shape),
+                         bm.index_map_jaxpr))
     n_scratch = getattr(gm, "num_scratch_operands", 0) or 0
     inner = params.get("jaxpr")
     scratch = tuple(_aval_dtype(v.aval)

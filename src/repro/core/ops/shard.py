@@ -48,11 +48,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
-__all__ = ["MeshSpec", "active_mesh", "unsharded_route", "abstract_meshes",
-           "sharded_gemm_2d", "sharded_attention_forward",
+__all__ = ["MeshSpec", "make_mesh", "active_mesh", "unsharded_route",
+           "abstract_meshes", "sharded_gemm_2d", "sharded_attention_forward",
            "sharded_attention_decode", "sharded_grouped_matmul"]
 
 
@@ -146,7 +145,9 @@ class MeshSpec:
         """AbstractMesh twin of ``build()`` — spec derivation with zero
         accelerators (tests, eval_shape)."""
         from jax.sharding import AbstractMesh
-        return AbstractMesh(tuple((a, s) for a, s in self._axis_items()))
+        items = self._axis_items()
+        return AbstractMesh(tuple(s for _, s in items),
+                            tuple(a for a, _ in items))
 
     def _axis_items(self) -> tuple[tuple[str, int], ...]:
         items = [("data", self.dp), ("expert", self.ep),
@@ -154,6 +155,16 @@ class MeshSpec:
         if self.pod > 1:
             items.insert(0, ("pod", self.pod))
         return tuple(items)
+
+
+def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...], devices):
+    """``jax.make_mesh`` with every axis ``Auto``: shardings come from
+    ``jit`` in/out shardings and ``shard_map`` specs and XLA propagates
+    the rest (the default since JAX 0.9, ``Explicit``, would instead
+    demand an output sharding on every reshape of a sharded array)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 @functools.lru_cache(maxsize=None)
@@ -164,9 +175,8 @@ def _build_mesh(spec: MeshSpec):
             f"mesh {spec.describe()} needs {spec.size} devices; "
             f"only {len(devices)} visible")
     items = spec._axis_items()
-    return jax.make_mesh(tuple(s for _, s in items),
-                         tuple(a for a, _ in items),
-                         devices=devices[:spec.size])
+    return make_mesh(tuple(s for _, s in items), tuple(a for a, _ in items),
+                     devices[:spec.size])
 
 
 # When True, the sharded dispatchers resolve MeshSpecs to ABSTRACT
@@ -250,8 +260,8 @@ def sharded_gemm_2d(impl, a: jax.Array, b: jax.Array, route) -> jax.Array:
             out = jax.lax.psum(out, "model")
         return out
 
-    return shard_map(body, mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)(a, b)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(a, b)
 
 
 # ============================================================== attention
@@ -319,8 +329,8 @@ def sharded_attention_forward(impl, q, k, v, *, causal, window, softcap,
             return _flash_over_kv(qb, kf, vf, mask_fn, inner, softcap,
                                   kv_chunk=min(kv_chunk, skv))
 
-    return shard_map(body, mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)(q, k, v)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(q, k, v)
 
 
 def sharded_attention_decode(impl, q, k_cache, v_cache, pos, *, window,
@@ -346,8 +356,9 @@ def sharded_attention_decode(impl, q, k_cache, v_cache, pos, *, window,
         return impl.fn.decode(qb, kb, vb, pb, window=window,
                               softcap=softcap, route=inner)
 
-    return shard_map(body, mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)(q, k_cache, v_cache, pos)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs,
+                         check_vma=False)(q, k_cache, v_cache, pos)
 
 
 # ============================================================== grouped EP
@@ -395,5 +406,6 @@ def sharded_grouped_matmul(impl, x, w, group_offsets, route) -> jax.Array:
                       route=inner)
         return jax.lax.psum(out, "expert")
 
-    return shard_map(body, mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)(x, w, group_offsets)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs,
+                         check_vma=False)(x, w, group_offsets)

@@ -74,7 +74,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import precision as prec
-from repro.kernels._compat import CompilerParams
 
 __all__ = ["FlashConfig", "flash_attention", "flash_decode"]
 
@@ -205,14 +204,20 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         l = l_ref[:, :1]
         o_ref[0, 0] = (acc_ref[...] /
                        jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m_ref[:, 0] +
-                         jnp.log(jnp.maximum(l_ref[:, 0], 1e-30)))
+        lse_ref[0, 0] = (m_ref[:, :1] +
+                         jnp.log(jnp.maximum(l_ref[:, :1], 1e-30)))
 
 
 def _fwd_impl(cfg: FlashConfig, qh, kh, vh, group: int,
               q_len: int, kv_len: int):
     """qh: (B, H, Sq_p, hd_p); kh/vh: (B, Kv, Skv_p, hd_p) — padded,
-    head-major.  Returns (out (B,H,Sq_p,hd_p) fp32, lse (B,H,Sq_p))."""
+    head-major.  Returns (out (B,H,Sq_p,hd_p) fp32, lse (B,H,Sq_p,1)).
+
+    The per-row vectors (``lse`` here, ``di`` in the backward) carry a
+    trailing unit dim: a ``(bq, 1)`` block is one the TPU tiling accepts
+    (sublane dim a multiple of 8, lane dim the whole array dim) at any
+    head count, and it broadcasts against the ``(bq, bkv)`` score tile
+    without a lane-to-sublane relayout."""
     b, h, sq_p, hd_p = qh.shape
     skv_p = kh.shape[2]
     bq = min(cfg.block_q, sq_p)
@@ -233,18 +238,18 @@ def _fwd_impl(cfg: FlashConfig, qh, kh, vh, group: int,
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bq, hd_p), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, h, i)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, sq_p, hd_p), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, sq_p), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, sq_p, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 128), jnp.float32),     # m (lane-replicated)
             pltpu.VMEM((bq, 128), jnp.float32),     # l
             pltpu.VMEM((bq, hd_p), jnp.float32),    # unnormalized acc
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=cfg.interpret,
@@ -286,8 +291,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref,
         k = k_ref[0, 0].astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
         do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0][:, None]                   # (bq, 1)
-        di = di_ref[0, 0][:, None]
+        lse = lse_ref[0, 0]                            # (bq, 1)
+        di = di_ref[0, 0]
         p, t, _ = _recompute_p(cfg, q, k, lse, i, j, bq, bkv,
                                q_len, kv_len)
         dp = _policy_dot(do, v, cfg.precision, trans_y=True)  # (bq, bkv)
@@ -317,8 +322,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
         k = k_ref[0, 0].astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
         do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0][:, None]
-        di = di_ref[0, 0][:, None]
+        lse = lse_ref[0, 0]                            # (bq, 1)
+        di = di_ref[0, 0]
         p, t, _ = _recompute_p(cfg, q, k, lse, i, j, bq, bkv,
                                q_len, kv_len)
         # dv = p^T do ; dk = ds^T q — transpose via swapped operands.
@@ -344,12 +349,12 @@ def _bwd_impl(cfg: FlashConfig, qh, kh, vh, out, lse, do, group: int,
     bkv = min(cfg.block_kv, skv_p)
     n_q, n_kv = sq_p // bq, skv_p // bkv
 
-    di = jnp.sum(out * do, axis=-1)                   # (B, H, Sq_p) fp32
+    di = jnp.sum(out * do, axis=-1, keepdims=True)    # (B,H,Sq_p,1) fp32
 
     q_spec = pl.BlockSpec((1, 1, bq, hd_p), lambda b, h, i, j: (b, h, i, 0))
     kv_spec = pl.BlockSpec((1, 1, bkv, hd_p),
                            lambda b, h, i, j, g=group: (b, h // g, j, 0))
-    row_spec = pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, h, i))
+    row_spec = pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0))
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, cfg=cfg, q_len=q_len,
@@ -359,7 +364,7 @@ def _bwd_impl(cfg: FlashConfig, qh, kh, vh, out, lse, do, group: int,
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, sq_p, hd_p), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bq, hd_p), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=cfg.interpret,
@@ -369,7 +374,8 @@ def _bwd_impl(cfg: FlashConfig, qh, kh, vh, out, lse, do, group: int,
     q_spec_t = pl.BlockSpec((1, 1, bq, hd_p), lambda b, h, j, i: (b, h, i, 0))
     kv_spec_t = pl.BlockSpec((1, 1, bkv, hd_p),
                              lambda b, h, j, i, g=group: (b, h // g, j, 0))
-    row_spec_t = pl.BlockSpec((1, 1, bq), lambda b, h, j, i: (b, h, i))
+    row_spec_t = pl.BlockSpec((1, 1, bq, 1),
+                              lambda b, h, j, i: (b, h, i, 0))
     dkv_out = pl.BlockSpec((1, 1, bkv, hd_p), lambda b, h, j, i: (b, h, j, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, cfg=cfg, q_len=q_len,
@@ -382,7 +388,7 @@ def _bwd_impl(cfg: FlashConfig, qh, kh, vh, out, lse, do, group: int,
                    jax.ShapeDtypeStruct((b, h, skv_p, hd_p), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((bkv, hd_p), jnp.float32),
                         pltpu.VMEM((bkv, hd_p), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=cfg.interpret,
@@ -580,7 +586,7 @@ def flash_decode(q, k_cache, v_cache, pos, *, window: int | None = None,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bsz, h, 1, hd_p), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(pos.astype(jnp.int32), qh, kh, vh)

@@ -19,10 +19,11 @@ outside the ring), so trash content never reaches the softmax.
 Quantized pools (int8 payload + per-(row, kv-head) fp32 scales) are
 dequantized in-kernel: the scale planes ride two more page-indirected
 block streams and multiply the tile right after load, before the
-policy-decomposed MXU dots.  The scale tile's trailing dim is
-``page_size`` (< 128 lanes for small pages) — fine in interpret mode,
-where this repo's CI runs; a lane-padded layout is the obvious follow-up
-for hardware.
+policy-decomposed MXU dots.  The scales are laid out ``(P, Kv, ps, 1)``
+so one (page, kv-head) scale tile is a ``(ps, 1)`` column: its last two
+block dims equal the array's, which the TPU tiling accepts at any
+``Kv`` and page size, and it broadcasts over the ``(ps, hd)`` payload
+tile as loaded.
 """
 
 from __future__ import annotations
@@ -35,7 +36,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.ops.paged import PagedKVCache
-from repro.kernels._compat import CompilerParams
 from repro.kernels.attention_fused import NEG_INF, _policy_dot, _round_up
 
 __all__ = ["flash_paged_decode"]
@@ -62,8 +62,8 @@ def _paged_kernel(pos_ref, table_ref, q_ref, k_ref, v_ref, *rest,
     k = k_ref[0, 0].astype(jnp.float32)               # (ps, hd)
     v = v_ref[0, 0].astype(jnp.float32)
     if quantized:
-        k = k * ks_ref[0, 0][:, None]
-        v = v * vs_ref[0, 0][:, None]
+        k = k * ks_ref[0, 0]                          # (ps, 1) scales
+        v = v * vs_ref[0, 0]
     s = _policy_dot(q, k, precision, trans_y=True)    # (1, ps)
     if softcap is not None:
         s = softcap * jnp.tanh(s / softcap)
@@ -141,12 +141,13 @@ def flash_paged_decode(q, cache: PagedKVCache, pos, *,
     operands = [qh, kh, vh]
     if cache.quantized:
         scale_spec = pl.BlockSpec(
-            (1, 1, ps),
+            (1, 1, ps, 1),
             lambda b, h, j, pos_ref, table_ref, g=grp:
-                (table_ref[b, j], h // g, 0))
+                (table_ref[b, j], h // g, 0, 0))
         in_specs += [scale_spec, scale_spec]
-        operands += [cache.k_scale.transpose(0, 2, 1),
-                     cache.v_scale.transpose(0, 2, 1)]
+        # (P, ps, Kv) -> (P, Kv, ps, 1): one (page, kv-head) column
+        operands += [cache.k_scale.transpose(0, 2, 1)[..., None],
+                     cache.v_scale.transpose(0, 2, 1)[..., None]]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -164,7 +165,7 @@ def flash_paged_decode(q, cache: PagedKVCache, pos, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bsz, h, 1, hd_p), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(pos.astype(jnp.int32), cache.page_table.astype(jnp.int32),

@@ -34,7 +34,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
 
 __all__ = ["batched_gemm", "batched_gemm_naive"]
 
@@ -94,7 +93,7 @@ def batched_gemm(
             pltpu.VMEM((tile, tile), jnp.bfloat16),
             pltpu.VMEM((tile, tile), jnp.bfloat16),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)
         ),
         interpret=interpret,
@@ -126,6 +125,6 @@ def batched_gemm_naive(
         ],
         out_specs=pl.BlockSpec((1, n, n), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((g, n, n), jnp.float32),
-        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(a, b)

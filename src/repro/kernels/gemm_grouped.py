@@ -63,7 +63,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import precision as prec
-from repro.kernels._compat import CompilerParams
 
 __all__ = ["GroupedConfig", "grouped_gemm", "tile_group_ids"]
 
@@ -185,7 +184,7 @@ def _gmm_call(cfg: GroupedConfig, x, w, gids, *, trans_w: bool):
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_rows, m), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=cfg.interpret,
     )(gids, x, w)
@@ -248,7 +247,7 @@ def _dw_call(cfg: GroupedConfig, x, dy, gids):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((cfg.num_groups, k, m),
                                        jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=cfg.interpret,
     )(gids, x, dy)

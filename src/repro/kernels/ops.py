@@ -9,9 +9,9 @@ Backends mirror the paper's three programming interfaces:
 
 The same registry serves the model stack (``peinsum`` routes) and the
 benchmarks, so models and benchmarks measure the identical code path.
-On this CPU container Pallas TPU kernels execute via ``interpret=True``
-(resolved once from the default backend); on TPU they compile through
-Mosaic. Tile shapes come from the shape-keyed cache in core.ops unless
+Off the TPU, Pallas kernels execute via ``interpret=True`` (resolved
+once from the default backend); on TPU they compile through Mosaic.
+Tile shapes come from the shape-keyed cache in core.ops unless
 the caller pins them; padding to block multiples happens in the router
 so arbitrary shapes work everywhere.
 

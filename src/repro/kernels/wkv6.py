@@ -29,7 +29,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
 
 __all__ = ["wkv6"]
 
@@ -125,7 +124,7 @@ def wkv6(r: jax.Array, k: jax.Array, v: jax.Array, logw: jax.Array,
             jax.ShapeDtypeStruct((b * h, kd, kd), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((kd, kd), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(rr, kk, vv, ww, uu)

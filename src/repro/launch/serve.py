@@ -39,6 +39,7 @@ from repro.core.ops import paged as paged_kv
 from repro.core.precision import PrecisionPolicy
 from repro.models import api
 from repro.runtime import serve_step
+from repro.runtime.compile_cache import enable_compile_cache
 
 __all__ = ["ServeEngine", "Request", "QueueFull", "RecoveryMismatch",
            "main"]
@@ -741,6 +742,7 @@ class ServeEngine:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCHS, default="gemma3-1b")
     ap.add_argument("--smoke", action="store_true")

@@ -47,6 +47,7 @@ from repro.data.pipeline import DataConfig, SyntheticLMDataset
 from repro.models import api
 from repro.optim import adamw
 from repro.runtime import mesh as meshlib
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.runtime.monitor import StepMonitor, run_header
 from repro.runtime.sharding import Sharder
 from repro.runtime.train_step import make_train_step
@@ -166,6 +167,7 @@ class _nullcontext:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCHS, default="gemma3-1b")
     ap.add_argument("--smoke", action="store_true",

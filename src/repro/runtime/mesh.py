@@ -30,7 +30,7 @@ import warnings
 
 import jax
 
-from repro.core.ops.shard import MeshSpec
+from repro.core.ops.shard import MeshSpec, make_mesh
 
 __all__ = [
     "MeshSpec",
@@ -59,7 +59,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     n = 1
     for s in shape:
         n *= s
-    return jax.make_mesh(shape, axes, devices=jax.devices()[:n])
+    return make_mesh(shape, axes, jax.devices()[:n])
 
 
 def make_test_mesh(data: int = 2, model: int = 2, expert: int = 1):
@@ -67,11 +67,10 @@ def make_test_mesh(data: int = 2, model: int = 2, expert: int = 1):
     count).  ``expert`` adds the EP axis only when asked, so existing
     (data, model) spec expectations are untouched."""
     if expert > 1:
-        return jax.make_mesh((data, expert, model),
-                             ("data", "expert", "model"),
-                             devices=jax.devices()[: data * expert * model])
-    return jax.make_mesh((data, model), ("data", "model"),
-                         devices=jax.devices()[: data * model])
+        return make_mesh((data, expert, model), ("data", "expert", "model"),
+                         jax.devices()[: data * expert * model])
+    return make_mesh((data, model), ("data", "model"),
+                     jax.devices()[: data * model])
 
 
 # --------------------------------------------------------- elastic shapes
@@ -165,9 +164,8 @@ def _mesh_for_spec(spec: MeshSpec, devices=None):
     if devices is None:
         return spec.build()
     items = spec._axis_items()
-    return jax.make_mesh(tuple(s for _, s in items),
-                         tuple(a for a, _ in items),
-                         devices=list(devices)[: spec.size])
+    return make_mesh(tuple(s for _, s in items), tuple(a for a, _ in items),
+                     list(devices)[: spec.size])
 
 
 def resharder_for(cfg, devices=None, *, policy=None, mode: str = "train"):

@@ -14,6 +14,8 @@ import collections
 import dataclasses
 import time
 
+import jax
+
 __all__ = ["StepMonitor", "run_header"]
 
 
@@ -28,11 +30,17 @@ def run_header(arch: str, *, policy=None, mesh=None) -> str:
     else:
         parts.append("mesh none (single-device)")
     if policy is not None:
-        from repro.core.ops import registry
+        from repro.core.ops import default_interpret, registry
         routed = " ".join(
             f"{fam}={policy.impl_for(fam)}"
             for fam in sorted(registry.families()))
         parts.append(routed)
+        # Off the TPU every Pallas kernel silently runs interpreted;
+        # say so, so a run that missed the chip cannot pass for one.
+        interpret = (default_interpret() if policy.interpret is None
+                     else policy.interpret)
+        mode = "interpret" if interpret else "compiled"
+        parts.append(f"{jax.default_backend()} (pallas {mode})")
     return " | ".join(parts)
 
 

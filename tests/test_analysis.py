@@ -22,7 +22,6 @@ import numpy as np
 import pytest
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.analysis import auditor
@@ -171,8 +170,8 @@ def _sharded(body_fn, in_specs, out_specs):
         if route.mesh is None or route.mesh.is_identity:
             return _f32_dot(a, b)
         mesh = shard._mesh_for(route.mesh)
-        return shard_map(body_fn, mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)(a, b)
+        return jax.shard_map(body_fn, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)(a, b)
     return run
 
 
