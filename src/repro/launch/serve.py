@@ -40,6 +40,7 @@ from repro.core.precision import PrecisionPolicy
 from repro.models import api
 from repro.runtime import serve_step
 from repro.runtime.compile_cache import enable_compile_cache
+from repro.runtime.monitor import span
 
 __all__ = ["ServeEngine", "Request", "QueueFull", "RecoveryMismatch",
            "main"]
@@ -132,6 +133,7 @@ class Request:
     t_submit: float | None = None
     t_admit: float | None = None
     t_first: float | None = None   # first token emitted (TTFT end)
+    t_last_token: float | None = None   # latest token emitted
     t_done: float | None = None
     wall_time: float | None = None
 
@@ -423,6 +425,10 @@ class ServeEngine:
         slot = self._free_slot()
         if slot is None:
             return False
+        with span("engine.admit", rid=req.rid, prompt_len=len(req.prompt)):
+            return self._admit_to(req, slot)
+
+    def _admit_to(self, req: Request, slot: int) -> bool:
         self._validate(req)
         if req.t_submit is None:
             req.t_submit = time.monotonic()
@@ -453,8 +459,10 @@ class ServeEngine:
             batch["image_embeds"] = jnp.zeros(
                 (1, self.cfg.num_image_tokens, self.cfg.d_model),
                 jnp.float32)
-        logits, cache1 = self._prefill(self.params, batch)
-        first = int(jnp.argmax(logits[0, -1]))
+        with span("engine.prefill"):
+            logits, cache1 = self._prefill(self.params, batch)
+        with span("engine.sync", what="first_token"):
+            first = int(jnp.argmax(logits[0, -1]))
         if resume:
             if first != req.out_tokens[-1]:
                 if alloc_map is not None:
@@ -465,7 +473,7 @@ class ServeEngine:
         else:
             req.t_admit = time.monotonic()
             req.out_tokens.append(first)
-            req.t_first = time.monotonic()
+            req.t_first = req.t_last_token = time.monotonic()
             self.tokens_generated += 1
             if self.metrics is not None:
                 self.metrics.histogram(
@@ -503,30 +511,32 @@ class ServeEngine:
             return jax.lax.dynamic_update_index_in_dim(
                 full, one[:, 0].astype(full.dtype), slot, axis=1)
 
-        if self.kv_layout == "paged":
-            # paged leaves take the page-scatter path; everything else
-            # (cross-attn KV, recurrent state) splices densely as ever
-            for sk, seg in cache1.items():
-                for pk, one in seg.items():
-                    full = self.cache[sk][pk]
-                    if isinstance(full, paged_kv.PagedKVCache):
-                        continue
-                    self.cache[sk][pk] = jax.tree.map(splice, full, one)
-            self._splice_paged(cache1, slot, alloc_map)
-            self._slot_pages[slot] = alloc_map
-        else:
-            self.cache = jax.tree.map(splice, self.cache, cache1)
-        # invariant (fresh k=1 and resumed k>1 alike): after k emitted
-        # tokens the cache holds prompt + out[:k-1], the next input is
-        # out[k-1] at position n_img + S + k - 1, and k counted against
-        # the budget — so a resumed slot ticks exactly like the dead one
-        # would have.
-        self.slot_req[slot] = req
-        self.last_tok = self.last_tok.at[slot].set(req.out_tokens[-1])
-        self.pos = self.pos.at[slot].set(n_img + len(toks))
-        self.active = self.active.at[slot].set(True)
-        self.remaining = self.remaining.at[slot].set(
-            req.max_new_tokens - len(req.out_tokens))
+        with span("engine.splice"):
+            if self.kv_layout == "paged":
+                # paged leaves take the page-scatter path; everything
+                # else (cross-attn KV, recurrent state) splices densely
+                for sk, seg in cache1.items():
+                    for pk, one in seg.items():
+                        full = self.cache[sk][pk]
+                        if isinstance(full, paged_kv.PagedKVCache):
+                            continue
+                        self.cache[sk][pk] = jax.tree.map(splice, full,
+                                                          one)
+                self._splice_paged(cache1, slot, alloc_map)
+                self._slot_pages[slot] = alloc_map
+            else:
+                self.cache = jax.tree.map(splice, self.cache, cache1)
+            # invariant (fresh k=1 and resumed k>1 alike): after k
+            # emitted tokens the cache holds prompt + out[:k-1], the
+            # next input is out[k-1] at position n_img + S + k - 1, and
+            # k counted against the budget — so a resumed slot ticks
+            # exactly like the dead one would have.
+            self.slot_req[slot] = req
+            self.last_tok = self.last_tok.at[slot].set(req.out_tokens[-1])
+            self.pos = self.pos.at[slot].set(n_img + len(toks))
+            self.active = self.active.at[slot].set(True)
+            self.remaining = self.remaining.at[slot].set(
+                req.max_new_tokens - len(req.out_tokens))
         return True
 
     # ------------------------------------------------------------- tick
@@ -539,29 +549,42 @@ class ServeEngine:
         bound) happens inside the jit'd tick. Returns the number of
         tokens decoded this tick (= active slots at entry).
         """
-        active_before = np.asarray(self.active)
-        n_active = int(active_before.sum())
-        if n_active == 0:
-            self._m_occupancy()
-            return 0
-        t0 = time.monotonic()
-        (self.cache, self.last_tok, self.pos, self.remaining,
-         self.active, finished) = self._tick(
-            self.params, self.cache, self.last_tok, self.pos,
-            self.active, self.remaining)
-        nxt = np.asarray(self.last_tok)
-        fin = np.asarray(finished)
-        now = time.monotonic()
-        for i in np.flatnonzero(active_before):
-            r = self.slot_req[i]
-            r.out_tokens.append(int(nxt[i]))
-            if fin[i]:
-                r.done = True
-                r.t_done = now
-                self.slot_req[i] = None
-                if self.kv_layout == "paged" and self._slot_pages[i]:
-                    self._free_pages(self._slot_pages[i], slot=int(i))
-                    self._slot_pages[i] = None
+        with span("engine.tick") as sp:
+            with span("engine.sync", what="active"):
+                active_before = np.asarray(self.active)
+            n_active = int(active_before.sum())
+            sp.attrs["active"] = n_active
+            if n_active == 0:
+                self._m_occupancy()
+                return 0
+            t0 = time.monotonic()
+            with span("engine.launch"):
+                (self.cache, self.last_tok, self.pos, self.remaining,
+                 self.active, finished) = self._tick(
+                    self.params, self.cache, self.last_tok, self.pos,
+                    self.active, self.remaining)
+            with span("engine.sync", what="tokens"):
+                nxt = np.asarray(self.last_tok)
+            with span("engine.sync", what="finished"):
+                fin = np.asarray(finished)
+            now = time.monotonic()
+            with span("engine.drain"):
+                gaps = []   # each request's time since its own last token
+                for i in np.flatnonzero(active_before):
+                    r = self.slot_req[i]
+                    r.out_tokens.append(int(nxt[i]))
+                    if r.t_last_token is not None:
+                        gaps.append(now - r.t_last_token)
+                    r.t_last_token = now
+                    if fin[i]:
+                        r.done = True
+                        r.t_done = now
+                        self.slot_req[i] = None
+                        if (self.kv_layout == "paged"
+                                and self._slot_pages[i]):
+                            self._free_pages(self._slot_pages[i],
+                                             slot=int(i))
+                            self._slot_pages[i] = None
         self.ticks += 1
         self.tokens_generated += n_active
         if self.metrics is not None:
@@ -570,12 +593,11 @@ class ServeEngine:
                 "serve_tick_seconds",
                 "one engine decode tick (all active slots)").observe(
                     dt, replica=self.replica)
-            # one tick = one token per active slot, so per-slot
-            # inter-token latency IS the tick duration
-            self.metrics.histogram(
+            itl = self.metrics.histogram(
                 "serve_inter_token_seconds",
-                "per-slot inter-token latency").observe(
-                    dt, replica=self.replica)
+                "per-request gap since that request's previous token")
+            for gap in gaps:
+                itl.observe(gap, replica=self.replica)
             self.metrics.counter(
                 "serve_tokens", "decoded tokens").inc(
                     n_active, replica=self.replica)
@@ -591,16 +613,17 @@ class ServeEngine:
         allow, tick, then age every request still in flight (deadlines
         count engine steps of ownership, so they are deterministic in
         virtual time and survive rehoming to another replica)."""
-        self._expire_due()
-        while self.queue and self.admit(self.queue[0]):
-            self.queue.popleft()
-        self._m_queue_depth()
-        n = self.tick()
-        for r in self.queue:
-            r.ticks_used += 1
-        for r in self.slot_req:
-            if r is not None:
+        with span("engine.step"):
+            self._expire_due()
+            while self.queue and self.admit(self.queue[0]):
+                self.queue.popleft()
+            self._m_queue_depth()
+            n = self.tick()
+            for r in self.queue:
                 r.ticks_used += 1
+            for r in self.slot_req:
+                if r is not None:
+                    r.ticks_used += 1
         return n
 
     @property

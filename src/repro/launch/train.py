@@ -48,7 +48,7 @@ from repro.models import api
 from repro.optim import adamw
 from repro.runtime import mesh as meshlib
 from repro.runtime.compile_cache import enable_compile_cache
-from repro.runtime.monitor import StepMonitor, run_header
+from repro.runtime.monitor import StepMonitor, run_header, span
 from repro.runtime.sharding import Sharder
 from repro.runtime.train_step import make_train_step
 
@@ -102,12 +102,18 @@ class TrainLoop:
             # out_shardings pinned to the in_shardings: shard_map'd ops
             # may bias XLA toward a different inferred output layout,
             # which trips the donation sharding check on step 2.
-            self.step_fn = jax.jit(
+            self.jitted_step = jax.jit(
                 step_fn, in_shardings=(pspecs, ospecs, None),
                 out_shardings=(pspecs, ospecs, None),
                 donate_argnums=(0, 1))
         else:
-            self.step_fn = jax.jit(step_fn, donate_argnums=(0, 1))
+            self.jitted_step = jax.jit(step_fn, donate_argnums=(0, 1))
+
+    def step_fn(self, params, opt, batch):
+        """``jitted_step`` (which donates ``params`` and ``opt``),
+        dispatched inside the span ``train.step``."""
+        with span("train.step"):
+            return self.jitted_step(params, opt, batch)
 
     # ------------------------------------------------------------ state
 
@@ -140,8 +146,9 @@ class TrainLoop:
                 batch = {k: jnp.asarray(v) for k, v in ds.batch(i).items()}
                 self.monitor.start()
                 params, opt, metrics = self.step_fn(params, opt, batch)
-                stats = self.monitor.stop()
+                # the step has run once its loss is on the host
                 history.append(float(metrics["loss"]))
+                stats = self.monitor.stop()
                 if stats.straggler:
                     print(f"[straggler] step {i}: {stats.last_s:.3f}s "
                           f"vs median {stats.median_s:.3f}s", flush=True)

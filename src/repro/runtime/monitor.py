@@ -1,4 +1,4 @@
-"""Step-time telemetry + straggler detection.
+"""Step-time telemetry, straggler detection and program spans.
 
 At 1000+ nodes the dominant failure mode short of a crash is a slow
 host (thermal throttle, flaky HBM, background daemon). The monitor
@@ -6,17 +6,79 @@ keeps a rolling window of per-step wall times, computes robust z-scores
 (median/MAD), and flags outliers; launch/train.py logs the flag and a
 real deployment wires it to the scheduler's drain-and-replace hook.
 Also accounts model FLOPs -> achieved FLOP/s for the live MFU readout.
+
+``span`` marks a stretch of host work: a ``jax.profiler.TraceAnnotation``
+(so a profiler trace shows it on the clock of the device's operations)
+and a record in a bounded in-memory ring, read by ``recent_spans``.
+Recording is always on; a span costs two to three microseconds when no
+trace is being taken.
 """
 
 from __future__ import annotations
 
 import collections
 import dataclasses
+import itertools
+import threading
 import time
 
 import jax
 
-__all__ = ["StepMonitor", "run_header"]
+__all__ = ["StepMonitor", "run_header", "span", "recent_spans",
+           "SPAN_RING"]
+
+SPAN_RING = 2 ** 17    # spans kept, the newest
+
+# (name, start_ns, end_ns, span_id, parent_id, attrs), monotonic_ns
+_spans: collections.deque = collections.deque(maxlen=SPAN_RING)
+_span_ids = itertools.count(1)
+_open = threading.local()   # per thread: ids of the spans open in it
+
+
+class _Span:
+    """One span; ``attrs`` set inside it reach the ring, not the
+    profiler's trace (which takes them on entry)."""
+
+    __slots__ = ("name", "attrs", "id", "parent", "start", "_ann",
+                 "_stack")
+
+    def __init__(self, name: str, attrs: dict):
+        self.name = name
+        self.attrs = attrs
+
+    def __enter__(self) -> "_Span":
+        stack = getattr(_open, "stack", None)
+        if stack is None:
+            stack = _open.stack = []
+        self._stack = stack
+        self.parent = stack[-1] if stack else None
+        self.id = next(_span_ids)
+        stack.append(self.id)
+        self._ann = jax.profiler.TraceAnnotation(self.name, **self.attrs)
+        self._ann.__enter__()
+        self.start = time.monotonic_ns()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        end = time.monotonic_ns()
+        self._ann.__exit__(*exc)
+        self._stack.pop()
+        _spans.append((self.name, self.start, end, self.id, self.parent,
+                       self.attrs))
+        return False
+
+
+def span(name: str, **attrs) -> _Span:
+    """Context manager: the host work inside it is the span ``name``.
+    Its parent is the innermost span open in the same thread."""
+    return _Span(name, attrs)
+
+
+def recent_spans() -> list[tuple]:
+    """The newest ``SPAN_RING`` finished spans in the order they closed,
+    each ``(name, start_ns, end_ns, span_id, parent_id, attrs)`` on
+    ``time.monotonic_ns``'s clock; ``parent_id`` is None at the top."""
+    return list(_spans)
 
 
 def run_header(arch: str, *, policy=None, mesh=None) -> str:

@@ -6,6 +6,7 @@ multi-device (forced-host-device) mesh."""
 import subprocess
 import sys
 import textwrap
+import time
 
 import jax
 import numpy as np
@@ -176,6 +177,37 @@ class TestMonitor:
         # odd window: plain middle element
         s = mon.observe(0.004)
         assert s.median_s == pytest.approx(0.003)
+
+    def test_train_loop_times_the_step_to_its_loss(self):
+        """TrainLoop.run stops the monitor once the loss is on the
+        host, so the step it times is the step that ran, not its
+        dispatch; the dispatch is the span ``train.step``."""
+        from repro.data.pipeline import DataConfig
+        from repro.launch.train import TrainLoop
+        from repro.runtime.monitor import recent_spans, span
+
+        cfg = get_smoke("starcoder2-15b")
+        loop = TrainLoop(cfg, policy=POLICY, opt_cfg=adamw.AdamWConfig(),
+                         data_cfg=DataConfig(global_batch=2, seq_len=8,
+                                             vocab_size=cfg.vocab_size))
+
+        class SlowLoss:   # a loss the device is still computing
+            def __float__(self):
+                time.sleep(0.05)
+                return 1.0
+
+        def step(params, opt, batch):
+            return params, opt, {"loss": SlowLoss()}
+
+        loop.jitted_step = step
+        with span("mark") as mark:
+            pass
+        loop.run(3, log_every=0)
+        assert len(loop.monitor.times) == 3
+        assert min(loop.monitor.times) >= 0.05
+        steps = [sp for sp in recent_spans()
+                 if sp[3] > mark.id and sp[0] == "train.step"]
+        assert len(steps) == 3
 
 
 MESH_PROG = textwrap.dedent("""

@@ -2,6 +2,8 @@
 text exposition, and the engine/monitor threading (duck-typed — the
 registry is handed in, never imported by launch/runtime)."""
 
+import time
+
 import numpy as np
 import pytest
 
@@ -113,6 +115,47 @@ class TestEngineThreading:
                                prompt=np.arange(2, 5, dtype=np.int32)))
         assert reg.counter(
             "serve_requests_rejected").value(replica="7") == 1
+
+
+    def test_inter_token_gap_covers_an_admission_between_ticks(self):
+        """``serve_inter_token_seconds`` is each request's gap since its
+        own previous token: a prefill made between two of its ticks
+        lands in the gap, and not in ``serve_tick_seconds``."""
+        jax = pytest.importorskip("jax")
+        from repro.configs import get_smoke
+        from repro.core.precision import PrecisionPolicy
+        from repro.launch.serve import ServeEngine
+        from repro.models import api
+
+        cfg = get_smoke("gemma3-1b")
+        eng = ServeEngine(cfg, batch_size=2, max_ctx=32,
+                          policy=PrecisionPolicy.uniform("f32"), eos_id=-1)
+        eng.load(api.init_params(jax.random.PRNGKey(0), cfg))
+        prompt = np.arange(2, 6, dtype=np.int32)
+        eng.run([Request(rid=0, prompt=prompt, max_new_tokens=3)])  # warm
+        reg = MetricsRegistry()
+        eng.metrics = reg
+        prefill = eng._prefill
+
+        def slow_prefill(*a):
+            time.sleep(0.3)
+            return prefill(*a)
+
+        eng._prefill = slow_prefill
+        eng.submit(Request(rid=1, prompt=prompt, max_new_tokens=4))
+        eng.step()     # admits rid 1, then ticks it
+        eng.submit(Request(rid=2, prompt=prompt, max_new_tokens=4))
+        eng.step()     # admits rid 2 (0.3 s) between two ticks of rid 1
+
+        def above(name, x):
+            cell = reg.histogram(name).labels(replica="0")
+            return sum(c for b, c in zip((0.0,) + cell.bounds, cell.counts)
+                       if b >= x)
+
+        assert above("serve_inter_token_seconds", 0.25) == 1
+        assert above("serve_tick_seconds", 0.25) == 0
+        assert reg.histogram("serve_inter_token_seconds").count(
+            replica="0") == 3   # rid 1 twice, rid 2 once
 
 
 class TestFaultToleranceSeries:
