@@ -209,10 +209,16 @@ class ServeEngine:
         self.metrics = metrics
         self.replica = replica
         self.params = None
+        # the tick and the splice rewrite the slot state: both donate
+        # the cache and the four slot vectors, so XLA updates them in
+        # place (never params, which every program reads)
         self._tick = jax.jit(serve_step.make_engine_tick(
-            cfg, self.policy, eos_id=eos_id, max_ctx=max_ctx))
+            cfg, self.policy, eos_id=eos_id, max_ctx=max_ctx),
+            donate_argnums=(1, 2, 3, 4, 5))
         self._prefill = jax.jit(
             serve_step.make_prefill(cfg, self.policy, s_ctx=max_ctx))
+        self._splice = jax.jit(serve_step.make_slot_splice(),
+                               donate_argnums=(0, 3, 4, 5, 6))
         # slot state (device-resident between ticks)
         self.cache = None
         self.slot_req: list[Request | None] = [None] * batch_size
@@ -406,10 +412,12 @@ class ServeEngine:
 
         Single-request prefill: runs the prompt through the prefill path
         and splices the resulting caches into the batch cache at the
-        slot index (tree-wise dynamic update on the batch axis). The
-        prompt's first sampled token counts against max_new_tokens and
-        may itself be EOS — then the request completes without ever
-        occupying a decode slot.
+        slot index with one compiled program, which also sets the
+        slot's four state vectors and donates the cache and those
+        vectors, so the slot is written in place. The prompt's first
+        sampled token counts against max_new_tokens and may itself be
+        EOS — then the request completes without ever occupying a
+        decode slot.
 
         A request arriving with ``out_tokens`` already populated is a
         RECOVERY re-admission (its previous replica died mid-decode):
@@ -501,42 +509,41 @@ class ServeEngine:
                 self._free_pages(alloc_map)
             return True
 
-        # The slot will actually decode: commit its prefill KV into the
-        # batch cache (splice runs after the early-done check, so
+        # The slot will actually decode: commit its prefill state into
+        # the batch state (splice runs after the early-done check, so
         # requests that finish in prefill never touch the cache).
-        def splice(full, one):
-            if not hasattr(one, "shape") or one.ndim < 2:
-                return full
-            # leaves are (count, B, ...) stacked per segment
-            return jax.lax.dynamic_update_index_in_dim(
-                full, one[:, 0].astype(full.dtype), slot, axis=1)
-
-        with span("engine.splice"):
+        # invariant (fresh k=1 and resumed k>1 alike): after k emitted
+        # tokens the cache holds prompt + out[:k-1], the next input is
+        # out[k-1] at position n_img + S + k - 1, and k counted against
+        # the budget — so a resumed slot ticks exactly like the dead one
+        # would have.
+        with span("engine.splice") as sp:
+            # device int32 scalars: one splice program serves every slot
+            scalars = jax.device_put(tuple(np.int32(v) for v in (
+                slot, req.out_tokens[-1], n_img + len(toks),
+                req.max_new_tokens - len(req.out_tokens))))
+            # paged leaves take the page scatter below; every other
+            # leaf (dense and cross-attn KV, recurrent state) goes
+            # through the splice program
+            dense = {sk: {pk: full for pk, full in seg.items()
+                          if not isinstance(full, paged_kv.PagedKVCache)}
+                     for sk, seg in self.cache.items()}
+            dense1 = {sk: {pk: cache1[sk][pk] for pk in seg}
+                      for sk, seg in dense.items()}
+            # whether the first buffer passed in is deleted after the
+            # call says whether the donation took or fell back to a copy
+            donor = jax.tree.leaves((dense, self.last_tok))[0]
+            (dense, self.last_tok, self.pos, self.active,
+             self.remaining) = self._splice(
+                dense, dense1, scalars[0], self.last_tok, self.pos,
+                self.active, self.remaining, *scalars[1:])
+            sp.attrs["donated"] = donor.is_deleted()
+            for sk, seg in dense.items():
+                self.cache[sk].update(seg)
             if self.kv_layout == "paged":
-                # paged leaves take the page-scatter path; everything
-                # else (cross-attn KV, recurrent state) splices densely
-                for sk, seg in cache1.items():
-                    for pk, one in seg.items():
-                        full = self.cache[sk][pk]
-                        if isinstance(full, paged_kv.PagedKVCache):
-                            continue
-                        self.cache[sk][pk] = jax.tree.map(splice, full,
-                                                          one)
                 self._splice_paged(cache1, slot, alloc_map)
                 self._slot_pages[slot] = alloc_map
-            else:
-                self.cache = jax.tree.map(splice, self.cache, cache1)
-            # invariant (fresh k=1 and resumed k>1 alike): after k
-            # emitted tokens the cache holds prompt + out[:k-1], the
-            # next input is out[k-1] at position n_img + S + k - 1, and
-            # k counted against the budget — so a resumed slot ticks
-            # exactly like the dead one would have.
             self.slot_req[slot] = req
-            self.last_tok = self.last_tok.at[slot].set(req.out_tokens[-1])
-            self.pos = self.pos.at[slot].set(n_img + len(toks))
-            self.active = self.active.at[slot].set(True)
-            self.remaining = self.remaining.at[slot].set(
-                req.max_new_tokens - len(req.out_tokens))
         return True
 
     # ------------------------------------------------------------- tick
@@ -551,18 +558,24 @@ class ServeEngine:
         """
         with span("engine.tick") as sp:
             with span("engine.sync", what="active"):
-                active_before = np.asarray(self.active)
+                # a host copy, never a view of the buffer the launch
+                # donates (np.asarray shares it on a CPU backend)
+                active_before = np.array(self.active)
             n_active = int(active_before.sum())
             sp.attrs["active"] = n_active
             if n_active == 0:
                 self._m_occupancy()
                 return 0
             t0 = time.monotonic()
-            with span("engine.launch"):
+            with span("engine.launch") as launch:
+                # active_before is on the host already: nothing reads
+                # the donated buffers after the launch
+                donor = jax.tree.leaves(self.cache)[0]
                 (self.cache, self.last_tok, self.pos, self.remaining,
                  self.active, finished) = self._tick(
                     self.params, self.cache, self.last_tok, self.pos,
                     self.active, self.remaining)
+                launch.attrs["donated"] = donor.is_deleted()
             with span("engine.sync", what="tokens"):
                 nxt = np.asarray(self.last_tok)
             with span("engine.sync", what="finished"):

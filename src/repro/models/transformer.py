@@ -110,22 +110,25 @@ def init_params(key, cfg: ModelConfig) -> dict:
 
 # =================================================================== cache
 
+def _zero_kv(shape: tuple[int, ...], dtype) -> AttnCache:
+    # K and V get buffers of their own: a program that donates the
+    # cache cannot take one buffer twice
+    return AttnCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
+
+
 def _init_sublayer_cache(kind: str, cfg: ModelConfig, batch: int,
                          s_ctx: int, stack: tuple[int, ...], dtype):
     if kind in ("attn", "attn_local"):
         s_c = s_ctx if (kind == "attn" or cfg.window is None) \
             else min(s_ctx, cfg.window)
-        z = jnp.zeros((*stack, batch, s_c, cfg.num_kv_heads, cfg.head_dim),
-                      dtype)
-        return AttnCache(k=z, v=z)
+        return _zero_kv((*stack, batch, s_c, cfg.num_kv_heads,
+                         cfg.head_dim), dtype)
     if kind == "cross_attn":
-        z = jnp.zeros((*stack, batch, cfg.encoder_seq, cfg.num_kv_heads,
-                       cfg.head_dim), dtype)
-        return AttnCache(k=z, v=z)
+        return _zero_kv((*stack, batch, cfg.encoder_seq, cfg.num_kv_heads,
+                         cfg.head_dim), dtype)
     if kind == "shared_attn":
-        z = jnp.zeros((*stack, batch, s_ctx, cfg.num_kv_heads, cfg.head_dim),
-                      dtype)
-        return AttnCache(k=z, v=z)
+        return _zero_kv((*stack, batch, s_ctx, cfg.num_kv_heads,
+                         cfg.head_dim), dtype)
     if kind == "mamba2":
         st = S.init_mamba_state(batch, cfg.d_model, cfg.ssm_head_dim,
                                 cfg.ssm_state, cfg.conv_width)

@@ -23,9 +23,10 @@ from repro.core.precision import PrecisionPolicy
 from repro.models import api
 from repro.models.attention import AttnCache
 
-__all__ = ["make_prefill", "make_decode", "make_engine_tick", "pad_cache",
-           "abstract_cache", "abstract_params", "attn_cache_walk",
-           "paged_classes", "init_paged_cache"]
+__all__ = ["make_prefill", "make_decode", "make_engine_tick",
+           "make_slot_splice", "pad_cache", "abstract_cache",
+           "abstract_params", "attn_cache_walk", "paged_classes",
+           "init_paged_cache"]
 
 # Either policy flavour routes every model matmul below (ExecutionPolicy
 # — or its legacy MatmulPolicy subclass — additionally selects the
@@ -169,6 +170,10 @@ def make_engine_tick(cfg: ModelConfig, policy: Policy, *,
     token-budget exhaustion, or context exhaustion. The host only ever
     reads back the small (B,) vectors — no per-token cache surgery or
     logits transfer on the hot path.
+
+    The engine jits it donating ``cache`` and the four vectors
+    (arguments 1-5, never ``params``), so XLA writes each slot's new KV
+    row into the cache it reads instead of copying the whole cache.
     """
 
     def tick(params, cache, last_tok, pos, active, remaining):
@@ -183,6 +188,42 @@ def make_engine_tick(cfg: ModelConfig, policy: Policy, *,
         return cache, nxt, new_pos, new_rem, active & ~finished, finished
 
     return tick
+
+
+def make_slot_splice():
+    """Admission of one prefilled request into one slot, jit-compatible.
+
+    splice(cache, cache1, slot, last_tok (B,), pos (B,), active (B,),
+           remaining (B,), first_tok, new_pos, new_remaining)
+        -> (cache, last_tok, pos, active, remaining)
+
+    ``cache1`` is the prefill's batch-1 cache.  Each of its leaves with
+    at least two dims goes into the batch leaf at ``slot`` along axis 1
+    (leaves are (count, B, ...) stacked per segment), cast to the batch
+    leaf's dtype; other leaves keep the batch leaf as it is.  The slot
+    then takes the request's next input token, position and remaining
+    budget, and becomes active.  ``slot`` and the three values are
+    traced int32 scalars, so one program serves every slot.
+
+    The engine jits it donating ``cache`` and the four vectors, so XLA
+    writes the slot's rows in place instead of a new whole cache.
+    """
+
+    def splice(cache, cache1, slot, last_tok, pos, active, remaining,
+               first_tok, new_pos, new_remaining):
+        def put(full, one):
+            if getattr(one, "ndim", 0) < 2:
+                return full
+            return jax.lax.dynamic_update_index_in_dim(
+                full, one[:, 0].astype(full.dtype), slot, axis=1)
+
+        return (jax.tree.map(put, cache, cache1),
+                last_tok.at[slot].set(first_tok),
+                pos.at[slot].set(new_pos),
+                active.at[slot].set(True),
+                remaining.at[slot].set(new_remaining))
+
+    return splice
 
 
 # ------------------------------------------------------------- abstract
