@@ -28,6 +28,7 @@ _MODULES = {
     "dbrx-132b": "dbrx_132b",
     "whisper-medium": "whisper_medium",
     "internvl2-76b": "internvl2_76b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 ARCHS: tuple[str, ...] = tuple(_MODULES)

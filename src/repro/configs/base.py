@@ -9,6 +9,9 @@ segment lists.
 
 Layer kinds:
   attn          GQA self-attention sublayer (+RoPE, optional window)
+  attn_local    ``attn`` under the sliding window
+  mla           multi-head latent attention (DeepSeek-V2): a latent KV
+                cache, expanded for prefill/train, absorbed for decode
   mlp           dense FFN sublayer (swiglu / squared_relu / gelu)
   moe           mixture-of-experts FFN sublayer
   mamba2        Mamba-2 SSD mixer sublayer
@@ -25,8 +28,11 @@ import warnings
 from repro.core.matmul import MatmulPolicy
 from repro.core.ops import ExecutionPolicy, TileConfig, normalize_backends
 
-__all__ = ["Segment", "ModelConfig", "ShapeSpec", "LM_SHAPES",
+__all__ = ["Segment", "Yarn", "ModelConfig", "ShapeSpec", "LM_SHAPES",
            "execution_policy_for", "matmul_policy_for"]
+
+MIXER_KINDS = ("attn", "attn_local", "mla", "mamba2", "rwkv6",
+               "shared_attn")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -39,6 +45,21 @@ class Segment:
 
     pattern: tuple[str, ...]
     count: int = 1
+
+
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN rope scaling (arXiv:2309.00071), as DeepSeek-V2 configures
+    it: frequencies interpolated by ``factor`` below the correction
+    range set by ``beta_fast``/``beta_slow`` over the original context,
+    and the softmax scale multiplied by ``mscale(mscale_all_dim)**2``."""
+
+    factor: float
+    original_max_pos: int
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -57,14 +78,23 @@ class ModelConfig:
     window: int | None = None        # sliding window for attn_local kind
     attn_logit_softcap: float | None = None
     qkv_bias: bool = False
+    # latent attention (mla): head_dim is the no-rope part of q and k
+    kv_lora_rank: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    yarn: Yarn | None = None
     # ffn
     d_ff: int = 0
     mlp_kind: str = "swiglu"         # swiglu | squared_relu | gelu
     mlp_bias: bool = False
     # moe
-    num_experts: int = 0
+    num_experts: int = 0             # experts this layer holds
     top_k: int = 0
     capacity_factor: float = 1.25
+    num_routed_experts: int = 0      # experts the router scores (0: held)
+    first_expert: int = 0            # router index of the first held one
+    moe_d_ff: int = 0                # routed expert width (0: d_ff)
+    shared_d_ff: int = 0             # shared experts as one FFN (0: none)
     # ssm (mamba2)
     ssm_state: int = 0
     ssm_heads: int = 0
@@ -104,12 +134,10 @@ class ModelConfig:
     def __post_init__(self) -> None:
         object.__setattr__(self, "backends",
                            normalize_backends(self.backends))
-        # "num_layers" counts mixer sublayers (attn / mamba2 / rwkv6 /
-        # shared_attn); mlp/moe sublayers ride along inside the same layer.
-        mixers = sum(
-            s.count * sum(k in ("attn", "attn_local", "mamba2", "rwkv6",
-                                "shared_attn") for k in s.pattern)
-            for s in self.segments)
+        # "num_layers" counts mixer sublayers (MIXER_KINDS); mlp/moe
+        # sublayers ride along inside the same layer.
+        mixers = sum(s.count * sum(k in MIXER_KINDS for k in s.pattern)
+                     for s in self.segments)
         if mixers != self.num_layers:
             raise ValueError(
                 f"{self.name}: segments define {mixers} mixer layers, "
@@ -122,6 +150,10 @@ class ModelConfig:
     @property
     def kv_dim(self) -> int:
         return self.num_kv_heads * self.head_dim
+
+    @property
+    def router_experts(self) -> int:
+        return self.num_routed_experts or self.num_experts
 
 
 def _arch_backends(cfg: ModelConfig) -> dict[str, str]:

@@ -1,4 +1,4 @@
-"""Family-dispatching facade: one API for all 10 architectures.
+"""Family-dispatching facade: one API for every architecture.
 
 runtime/, launch/ and tests/ talk to models exclusively through this
 module, so train_step / serve_step / dryrun are arch-agnostic.

@@ -106,10 +106,12 @@ def _values(p, v, policy):
 def _flash_over_kv(q, k, v, mask_fn, policy, softcap, kv_chunk: int):
     """Online-softmax attention, scanning KV chunks (flash-style).
 
-    q: (B,Q,Kv,G,hd); k/v: (B,S,Kv,hd). mask_fn(q_idx, k_idx) -> bool
-    keep-mask broadcastable to (Q, chunk). Returns (B,Q,Kv,G,hd) fp32.
+    q: (B,Q,Kv,G,hd); k: (B,S,Kv,hd); v: (B,S,Kv,vd). mask_fn(q_idx,
+    k_idx) -> bool keep-mask broadcastable to (Q, chunk). Returns
+    (B,Q,Kv,G,vd) fp32.
     """
-    b, qlen, kvh, grp, hd = q.shape
+    b, qlen, kvh, grp, _ = q.shape
+    vd = v.shape[-1]
     s = k.shape[1]
     if s % kv_chunk:  # pad keys to a chunk multiple; mask the tail
         pad = kv_chunk - s % kv_chunk
@@ -138,7 +140,7 @@ def _flash_over_kv(q, k, v, mask_fn, policy, softcap, kv_chunk: int):
 
     m0 = jnp.full((b, kvh, grp, qlen), NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, kvh, grp, qlen), jnp.float32)
-    acc0 = jnp.zeros((b, qlen, kvh, grp, hd), jnp.float32)
+    acc0 = jnp.zeros((b, qlen, kvh, grp, vd), jnp.float32)
     # Nested remat: without it the backward loads STACKED per-chunk f32
     # score/prob tensors (B,Kv,G,Q,c) x n_chunks from HBM — the dominant
     # memory term of every train/prefill cell at baseline. Recomputing

@@ -27,6 +27,14 @@ kernel-family backend carried by the matmul route:
   product), which is what makes decode under continuous batching
   token-exact.
 
+Expert share: a layer may hold only some of the experts its router
+scores (``num_experts`` held out of the router's width, from
+``first_expert``), as one chip of an expert-parallel deployment does.
+It routes every token over all router outputs and computes only the
+part of the result its own experts give; assignments to experts held
+elsewhere are dropped here.  Shared experts (``p["shared"]``, one FFN
+every token meets) are added on top.
+
 Sharding: the expert dim maps to the `model` mesh axis when divisible
 (dbrx: 16 experts on 16-way model axis = true EP); otherwise experts
 stay replicated and the FFN hidden dim takes the TP sharding (mixtral:
@@ -49,17 +57,25 @@ __all__ = ["init_moe", "moe_ffn"]
 
 
 def init_moe(key, d: int, d_ff: int, num_experts: int, mlp_kind: str,
-             *, stack: tuple[int, ...] = ()) -> dict:
+             *, stack: tuple[int, ...] = (), router_experts: int = 0,
+             shared_d_ff: int = 0) -> dict:
+    """Router over ``router_experts`` (default: the ``num_experts``
+    held), the held experts' stacks, and a shared FFN of width
+    ``shared_d_ff`` where it is not 0."""
     kr, k1, k2, k3 = jax.random.split(key, 4)
     estack = (*stack, num_experts)
     p = {
-        "router": L.init_linear(kr, d, num_experts, stack=stack),
+        "router": L.init_linear(kr, d, router_experts or num_experts,
+                                stack=stack),
         "wi": L.init_linear(k1, d, d_ff, stack=estack),
         "wo": L.init_linear(k3, d_ff, d, stack=estack,
                             scale=d_ff ** -0.5),
     }
     if mlp_kind == "swiglu":
         p["wg"] = L.init_linear(k2, d, d_ff, stack=estack)
+    if shared_d_ff:
+        p["shared"] = L.init_mlp(jax.random.fold_in(key, 4), d, shared_d_ff,
+                                 mlp_kind, stack=stack)
     return p
 
 
@@ -81,7 +97,9 @@ def _capacity_ffn(p: dict, xf: jax.Array, gate_vals, expert_idx, *,
     Position-in-expert via a (T*k, E) cumsum; assignments past
     ``capacity`` are dropped; the (E, C, D) gather feeds the vmap-
     batched ``ecd,edf->ecf`` expert einsum; the combine is a scatter-add
-    weighted by router probabilities.  xf: (T, D) -> (T, D) fp32.
+    weighted by router probabilities.  An ``expert_idx`` of
+    ``num_experts`` (an expert held elsewhere) lands on the dropped
+    dummy row.  xf: (T, D) -> (T, D) fp32.
     """
     t = xf.shape[0]
     flat_expert = expert_idx.reshape(-1)                          # (T*k,)
@@ -180,7 +198,7 @@ def _sorted_ffn(p: dict, xf: jax.Array, gate_vals, expert_idx, *,
 def moe_ffn(p: dict, x: jax.Array, *, num_experts: int, top_k: int,
             capacity_factor: float, mlp_kind: str, policy: str | Route,
             router_policy: str = "f32", dropless: bool = False,
-            ) -> tuple[jax.Array, jax.Array]:
+            first_expert: int = 0) -> tuple[jax.Array, jax.Array]:
     """x: (B, S, D) -> (out (B, S, D), aux_loss scalar).
 
     Router runs in fp32 regardless of the matmul policy (standard
@@ -192,15 +210,21 @@ def moe_ffn(p: dict, x: jax.Array, *, num_experts: int, top_k: int,
     semantics, any other registered impl runs the sort-based dropless
     path.
     ``dropless=True`` lifts the reference path's capacity to the worst
-    case (t * top_k) — used on the DECODE path, where capacity-based
-    dropping would make generation depend on batch composition.  The
-    sorted path is dropless by construction, so the flag is moot there.
+    case (t: a token meets each expert at most once) — used at
+    inference, where capacity-based dropping would make generation
+    depend on batch composition.  The sorted path is dropless by
+    construction, so the flag is moot there.
+
+    The router has ``p["router"]["w"].shape[-1]`` outputs; the layer
+    holds experts ``first_expert .. first_expert + num_experts - 1`` of
+    them (module docstring).
     """
     b, s, d = x.shape
     t = b * s
     dtype = x.dtype
     xf = x.reshape(t, d)
 
+    scored = p["router"]["w"].shape[-1]
     logits = peinsum("td,de->te", xf, p["router"]["w"], router_policy)
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)  # (T, E)
     gate_vals, expert_idx = jax.lax.top_k(probs, top_k)          # (T, k)
@@ -209,17 +233,25 @@ def moe_ffn(p: dict, x: jax.Array, *, num_experts: int, top_k: int,
     # counts ALL top-k assignments, not just the top-1 column, so a
     # top-k>1 router is pushed to balance its full assignment load.
     density = jnp.mean(
-        jax.nn.one_hot(expert_idx, num_experts, dtype=jnp.float32),
+        jax.nn.one_hot(expert_idx, scored, dtype=jnp.float32),
         axis=(0, 1))
     density_proxy = jnp.mean(probs, axis=0)
-    aux_loss = num_experts * jnp.sum(density * density_proxy)
+    aux_loss = scored * jnp.sum(density * density_proxy)
 
     route = ops.as_route(policy)
-    if route.uses_reference("grouped"):
+    reference = route.uses_reference("grouped")
+    if scored != num_experts:
+        # a share: assignments to experts held elsewhere go to the
+        # capacity path's dropped row, or to expert 0 with no weight
+        local = expert_idx - first_expert
+        held = (local >= 0) & (local < num_experts)
+        gate_vals = jnp.where(held, gate_vals, 0.0)
+        expert_idx = jnp.where(held, local, num_experts if reference else 0)
+    if reference:
         if dropless:
-            capacity = t * top_k        # worst case: every slot one expert
+            capacity = t                # worst case: every token one expert
         else:
-            capacity = int(capacity_factor * top_k * t / num_experts)
+            capacity = int(capacity_factor * top_k * t / scored)
             capacity = max(capacity, top_k)
         out = _capacity_ffn(p, xf, gate_vals, expert_idx,
                             num_experts=num_experts, top_k=top_k,
@@ -229,4 +261,7 @@ def moe_ffn(p: dict, x: jax.Array, *, num_experts: int, top_k: int,
         out = _sorted_ffn(p, xf, gate_vals, expert_idx,
                           num_experts=num_experts, top_k=top_k,
                           mlp_kind=mlp_kind, route=route, dtype=dtype)
-    return out.astype(dtype).reshape(b, s, d), aux_loss
+    out = out.astype(dtype)
+    if "shared" in p:
+        out = out + L.mlp(p["shared"], xf, mlp_kind, policy)
+    return out.reshape(b, s, d), aux_loss

@@ -11,6 +11,10 @@ zamba2's `shared_attn` blocks read their params from a single shared
 tree (closure), not from the scanned stack — the paper-pool's
 "shared attention" semantics — while their KV caches remain
 per-occurrence (stacked).
+
+`mla` sublayers (DeepSeek-V2 latent attention, ``models/mla.py``) keep
+a ``LatentCache`` of ``kv_lora_rank + qk_rope_dim`` values per token
+(two leaves) instead of per-head K and V.
 """
 
 from __future__ import annotations
@@ -23,6 +27,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig, Segment
 from repro.core.precision import PrecisionPolicy
 from repro.models import layers as L
+from repro.models import mla as MLA
 from repro.models import moe as M
 from repro.models import rwkv as R
 from repro.models import ssm as S
@@ -45,6 +50,9 @@ def _init_sublayer(key, kind: str, cfg: ModelConfig,
             **init_attn(kb, cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
                         cfg.head_dim, bias=cfg.qkv_bias, stack=stack),
         }
+    if kind == "mla":
+        return {"norm": L.init_rmsnorm(cfg.d_model, stack=stack),
+                **MLA.init_mla(kb, cfg, stack=stack)}
     if kind == "mlp":
         return {
             "norm": L.init_rmsnorm(cfg.d_model, stack=stack),
@@ -54,8 +62,10 @@ def _init_sublayer(key, kind: str, cfg: ModelConfig,
     if kind == "moe":
         return {
             "norm": L.init_rmsnorm(cfg.d_model, stack=stack),
-            **M.init_moe(kb, cfg.d_model, cfg.d_ff, cfg.num_experts,
-                         cfg.mlp_kind, stack=stack),
+            **M.init_moe(kb, cfg.d_model, cfg.moe_d_ff or cfg.d_ff,
+                         cfg.num_experts, cfg.mlp_kind, stack=stack,
+                         router_experts=cfg.router_experts,
+                         shared_d_ff=cfg.shared_d_ff),
         }
     if kind == "mamba2":
         return S.init_mamba2(kb, cfg.d_model, cfg.ssm_head_dim,
@@ -123,6 +133,10 @@ def _init_sublayer_cache(kind: str, cfg: ModelConfig, batch: int,
             else min(s_ctx, cfg.window)
         return _zero_kv((*stack, batch, s_c, cfg.num_kv_heads,
                          cfg.head_dim), dtype)
+    if kind == "mla":
+        return MLA.LatentCache(
+            latent=jnp.zeros((*stack, batch, s_ctx, cfg.kv_lora_rank), dtype),
+            k_rope=jnp.zeros((*stack, batch, s_ctx, cfg.qk_rope_dim), dtype))
     if kind == "cross_attn":
         return _zero_kv((*stack, batch, cfg.encoder_seq, cfg.num_kv_heads,
                          cfg.head_dim), dtype)
@@ -158,8 +172,10 @@ def init_cache(cfg: ModelConfig, batch: int, s_ctx: int,
 
 def _apply_sublayer(kind: str, p: dict, x: jax.Array, *, cfg: ModelConfig,
                     policy: PrecisionPolicy, mode: str, cache, pos,
-                    shared: dict | None, enc_x: jax.Array | None):
-    """Returns (x, new_cache, aux_loss)."""
+                    shared: dict | None, enc_x: jax.Array | None,
+                    layer=None):
+    """Returns (x, new_cache, aux_loss).  ``layer``: the index into a
+    stacked cache this sublayer decodes against in place (`mla`)."""
     aux = jnp.zeros((), jnp.float32)
     if kind in ("attn", "attn_local") or kind == "shared_attn":
         if kind == "shared_attn":
@@ -203,6 +219,12 @@ def _apply_sublayer(kind: str, p: dict, x: jax.Array, *, cfg: ModelConfig,
             cross_kv=ckv, pos=pos)
         new_cache = ckv if mode in ("prefill", "decode") else {}
         return x + out, new_cache, aux
+    if kind == "mla":
+        xn = L.rmsnorm(p["norm"], x, cfg.norm_eps)
+        out, new_cache = MLA.mla_attention(
+            p, xn, cfg=cfg, mode=mode, policy=policy.for_("attention"),
+            cache=cache if mode == "decode" else None, pos=pos, layer=layer)
+        return x + out, ({} if new_cache is None else new_cache), aux
     if kind == "mlp":
         xn = L.rmsnorm(p["norm"], x, cfg.norm_eps)
         return x + L.mlp(p, xn, cfg.mlp_kind, policy.for_("mlp")), {}, aux
@@ -211,7 +233,8 @@ def _apply_sublayer(kind: str, p: dict, x: jax.Array, *, cfg: ModelConfig,
         out, aux = M.moe_ffn(
             p, xn, num_experts=cfg.num_experts, top_k=cfg.top_k,
             capacity_factor=cfg.capacity_factor, mlp_kind=cfg.mlp_kind,
-            policy=policy.for_("moe"), dropless=(mode == "decode"))
+            policy=policy.for_("moe"), dropless=(mode != "train"),
+            first_expert=cfg.first_expert)
         return x + out, {}, aux
     if kind == "mamba2":
         x, new_state = S.mamba2_layer(
@@ -233,30 +256,52 @@ def _apply_segment(seg_params: dict, seg: Segment, x: jax.Array, *,
                    cfg: ModelConfig, policy: PrecisionPolicy, mode: str,
                    seg_cache: dict | None, pos, shared, enc_x,
                    remat: bool = False):
-    """Scan `seg.count` periods of the pattern. Returns (x, new_cache, aux)."""
+    """Scan `seg.count` periods of the pattern. Returns (x, new_cache, aux).
+
+    In decode, latent (`mla`) caches ride in the scan's carry with the
+    layer index: each layer writes its new rows into the stacked buffer
+    and reads its own slice there, where a cache scanned over as xs/ys
+    is copied whole on every call."""
     n_pos = len(seg.pattern)
     has_cache = seg_cache is not None
+    carried = tuple(f"pos{j}" for j, kind in enumerate(seg.pattern)
+                    if kind == "mla") if has_cache and mode == "decode" \
+        else ()
 
     def period(carry, xs):
         from repro.runtime.act_sharding import constrain
-        x, aux = carry
+        x, aux, *held = carry
         p_stack, c_stack = xs
+        stacks, layer = held if held else ({}, None)
         new_caches = {}
         for j, kind in enumerate(seg.pattern):
-            c_j = c_stack.get(f"pos{j}") if has_cache else None
+            key = f"pos{j}"
+            c_j = stacks.get(key, c_stack.get(key) if has_cache else None)
             x, nc, a = _apply_sublayer(
-                kind, p_stack[f"pos{j}"], x, cfg=cfg, policy=policy,
-                mode=mode, cache=c_j, pos=pos, shared=shared, enc_x=enc_x)
+                kind, p_stack[key], x, cfg=cfg, policy=policy,
+                mode=mode, cache=c_j, pos=pos, shared=shared, enc_x=enc_x,
+                layer=layer if key in stacks else None)
             x = constrain(x, "residual")  # pin (B: dp, S, D: replicated)
-            new_caches[f"pos{j}"] = nc
+            if key in stacks:
+                stacks, nc = {**stacks, key: nc}, {}
+            new_caches[key] = nc
             aux = aux + a
+        if held:
+            return (x, aux, stacks, layer + 1), new_caches
         return (x, aux), new_caches
 
     body = jax.checkpoint(period) if remat else period
-    xs = (seg_params, seg_cache if has_cache else
+    xs = (seg_params, {k: ({} if k in carried else c)
+                       for k, c in seg_cache.items()} if has_cache else
           {f"pos{j}": {} for j in range(n_pos)})
-    (x, aux), new_cache = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)), xs)
+    init = (x, jnp.zeros((), jnp.float32))
+    if carried:
+        init += ({k: seg_cache[k] for k in carried},
+                 jnp.zeros((), jnp.int32))
+    out, new_cache = jax.lax.scan(body, init, xs)
+    x, aux = out[:2]
+    if carried:
+        new_cache.update(out[2])
     return x, new_cache, aux
 
 

@@ -22,6 +22,7 @@ from repro.core.ops.paged import PagedKVCache
 from repro.core.precision import PrecisionPolicy
 from repro.models import api
 from repro.models.attention import AttnCache
+from repro.models.mla import LatentCache
 
 __all__ = ["make_prefill", "make_decode", "make_engine_tick",
            "make_slot_splice", "pad_cache", "abstract_cache",
@@ -65,6 +66,12 @@ def pad_cache(cache: dict, cfg: ModelConfig, s_ctx: int) -> dict:
                     pad = [(0, 0)] * c.k.ndim
                     pad[2] = (0, cap - cur)
                     c = AttnCache(k=jnp.pad(c.k, pad), v=jnp.pad(c.v, pad))
+            elif isinstance(c, LatentCache) and c.latent.shape[2] < s_ctx:
+                # (count, B, S, width): grows like a full-context cache;
+                # the paged pool leaves it dense
+                pad = [(0, 0), (0, 0), (0, s_ctx - c.latent.shape[2]),
+                       (0, 0)]
+                c = LatentCache(*(jnp.pad(x, pad) for x in c))
             new_seg[f"pos{j}"] = c
         out[f"seg{i}"] = new_seg
     return out

@@ -229,6 +229,10 @@ class Sharder:
         if ("wkv" in path or "ssd" in path) and len(shape) == 5:
             _, b, h, _, _ = shape
             return P(None, self._dp(b), self._m(h), None, None)
+        # Latent (mla) caches: (count, B, S, rank | rope), read whole by
+        # every head, so only the batch shards.
+        if path.endswith(("/latent", "/k_rope")):
+            return P(None, self._dp(shape[1]), None, None)
         # Stacked attn caches: (count, B, S, Kv, hd)
         if len(shape) == 5:
             _, b, s, kv, _ = shape

@@ -68,6 +68,14 @@ class TestArchSmoke:
             "internvl2-76b": dict(num_layers=80, d_model=8192, num_heads=64,
                                   num_kv_heads=8, d_ff=28672,
                                   vocab_size=128256),
+            "deepseek-v2-lite": dict(num_layers=27, d_model=2048,
+                                     num_heads=16, num_kv_heads=16,
+                                     head_dim=128, kv_lora_rank=512,
+                                     qk_rope_dim=64, v_head_dim=128,
+                                     d_ff=10944, moe_d_ff=1408,
+                                     shared_d_ff=2816, vocab_size=102400,
+                                     num_experts=64, num_routed_experts=64,
+                                     top_k=6),
         }[arch]
         for k, v in assigned.items():
             assert getattr(cfg, k) == v, f"{arch}.{k}: {getattr(cfg, k)} != {v}"

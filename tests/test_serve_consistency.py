@@ -99,6 +99,7 @@ def _roundtrip(arch: str, s_total: int = 12, s_prefix: int = 7,
     "rwkv6-7b",         # rwkv6 recurrence
     "whisper-medium",   # enc-dec with cross-attention cache
     "internvl2-76b",    # vlm image-prefix position offsets
+    "deepseek-v2-lite",  # latent attention, shared experts, expert share
 ])
 def test_prefill_decode_matches_forward(arch):
     _roundtrip(arch)
@@ -148,6 +149,7 @@ def _engine(cfg, params, batch_size):
     "rwkv6-7b",         # rwkv6 recurrence
     "whisper-medium",   # enc-dec with cross-attention cache
     "internvl2-76b",    # vlm image-prefix position offsets
+    "deepseek-v2-lite",  # latent attention, shared experts, expert share
 ])
 def test_staggered_admission_matches_single(arch):
     """4 requests with different prompt lengths and token budgets on a

@@ -118,3 +118,37 @@ def test_grouped_gemm_forward(one_chip):
     w = _spec(one_chip, (8, D_MODEL, D_FF))
     offsets = _spec(one_chip, (9,), jnp.int32)
     _assert_kernel_compiles(grouped_gemm, x, w, offsets)
+
+
+def test_latent_decode_tick_writes_the_cache_in_place(one_chip):
+    """The deepseek-v2-lite serving cell's tick (6 layers, 8 of 64
+    experts, 64 slots of 8192): its temporaries stay under one whole
+    latent cache.  Scanned as xs/ys, or kept as one 576-wide leaf (which
+    the TPU stores sequence-minor, so every row write relays it out),
+    the cache was copied whole every tick: 6.6 and 4.4 GB of
+    temporaries against 1.6."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.configs.base import Segment, execution_policy_for
+    from repro.runtime import serve_step
+
+    cfg = dataclasses.replace(
+        get_config("deepseek-v2-lite"), num_layers=6, num_experts=8,
+        segments=(Segment(("mla", "mlp"), 1), Segment(("mla", "moe"), 5)))
+    b, ctx = 64, 8192
+    policy = execution_policy_for(cfg, require={"attention": ("decode",)})
+    spec = lambda tree: jax.tree.map(
+        lambda x: _spec(one_chip, x.shape, x.dtype), tree)
+    cache = spec(serve_step.abstract_cache(cfg, b, ctx))
+    vec = lambda dtype: _spec(one_chip, (b,), dtype)
+    tick = jax.jit(serve_step.make_engine_tick(cfg, policy, eos_id=-1,
+                                               max_ctx=ctx),
+                   donate_argnums=(1, 2, 3, 4, 5))
+    mem = tick.lower(spec(serve_step.abstract_params(cfg)), cache,
+                     vec(jnp.int32), vec(jnp.int32), vec(bool),
+                     vec(jnp.int32)).compile().memory_analysis()
+    cache_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree.leaves(cache))
+    assert mem.temp_size_in_bytes < cache_bytes
+    assert mem.alias_size_in_bytes >= cache_bytes
