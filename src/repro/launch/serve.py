@@ -232,7 +232,15 @@ class ServeEngine:
         self.tokens_generated = 0
 
     def load(self, params) -> None:
-        self.params = params
+        """Hold ``params`` for serving and build the empty cache.
+
+        Each float32 leaf that every read in the tick and the prefill
+        rounds to bf16 first (under this engine's route) is rounded
+        once here, so the programs read half the bytes and compute the
+        same numbers.  The engine holds its own tree: bf16 copies of
+        those leaves and the caller's arrays for the rest, which are
+        left as they are.
+        """
         # cache in the activation dtype: decode writes splice activation
         # rows in, and a dtype mismatch would silently round-trip keys
         # through a narrower type only on the batched path
@@ -250,6 +258,36 @@ class ServeEngine:
         else:
             self.cache = api.init_cache(
                 self.cfg, self.batch, self.max_ctx, dtype)
+        with span("engine.load") as sp:
+            n_img = (self.cfg.num_image_tokens
+                     if self.cfg.family == "vlm" else 0)
+            prompt = np.zeros(max(1, min(16, self.max_ctx - 1 - n_img)),
+                              np.int32)
+            which = serve_step.bf16_read_leaves(params, [
+                (self._tick, (self.cache, self.last_tok, self.pos,
+                              self.active, self.remaining)),
+                (self._prefill, (self._prefill_batch(prompt),))])
+            self.params = serve_step.round_leaves(params, which)
+            held = jax.tree.leaves(self.params)
+            rounded = [x for x, w in zip(held, jax.tree.leaves(which))
+                       if w]
+            sp.attrs.update(
+                rounded_leaves=len(rounded),
+                rounded_bytes=sum(x.nbytes for x in rounded),
+                kept_f32_bytes=sum(x.nbytes for x in held
+                                   if x.dtype == jnp.float32))
+
+    def _prefill_batch(self, toks: np.ndarray) -> dict:
+        """The prefill program's batch for one prompt ``toks`` (S,)."""
+        batch = {"tokens": jnp.asarray(toks)[None]}          # (1, S)
+        if self.cfg.family == "audio":
+            batch["frames"] = jnp.zeros(
+                (1, self.cfg.encoder_seq, self.cfg.d_model), jnp.float32)
+        if self.cfg.family == "vlm":
+            batch["image_embeds"] = jnp.zeros(
+                (1, self.cfg.num_image_tokens, self.cfg.d_model),
+                jnp.float32)
+        return batch
 
     # ------------------------------------------------------------ slots
 
@@ -458,15 +496,7 @@ class ServeEngine:
         toks = (np.concatenate([np.asarray(req.prompt, np.int32),
                                 np.asarray(req.out_tokens[:-1], np.int32)])
                 if resume else np.asarray(req.prompt, np.int32))
-        prompt = jnp.asarray(toks)[None]                    # (1, S[+k-1])
-        batch = {"tokens": prompt}
-        if self.cfg.family == "audio":
-            batch["frames"] = jnp.zeros(
-                (1, self.cfg.encoder_seq, self.cfg.d_model), jnp.float32)
-        if self.cfg.family == "vlm":
-            batch["image_embeds"] = jnp.zeros(
-                (1, self.cfg.num_image_tokens, self.cfg.d_model),
-                jnp.float32)
+        batch = self._prefill_batch(toks)                  # (1, S[+k-1])
         with span("engine.prefill"):
             logits, cache1 = self._prefill(self.params, batch)
         with span("engine.sync", what="first_token"):

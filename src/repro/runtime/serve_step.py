@@ -6,6 +6,10 @@ capacity (attention caches grow in place afterwards; ring-buffer local
 caches are already window-sized; recurrent states are O(1)). ``decode``
 is the cell lowered for the ``decode_32k`` / ``long_500k`` dry-runs —
 one new token against the full-capacity cache, NOT a train step.
+
+``bf16_read_leaves`` finds, from the jaxprs of the tick and the prefill,
+the float32 weights that every read rounds to bf16 first, and
+``round_leaves`` rounds them once, so the engine serves those in bf16.
 """
 
 from __future__ import annotations
@@ -14,6 +18,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax.extend.core import ClosedJaxpr, Literal
 
 from repro.configs.base import ModelConfig
 from repro.core.ops import ExecutionPolicy
@@ -27,7 +32,7 @@ from repro.models.mla import LatentCache
 __all__ = ["make_prefill", "make_decode", "make_engine_tick",
            "make_slot_splice", "pad_cache", "abstract_cache",
            "abstract_params", "attn_cache_walk", "paged_classes",
-           "init_paged_cache"]
+           "init_paged_cache", "bf16_read_leaves", "round_leaves"]
 
 # Either policy flavour routes every model matmul below (ExecutionPolicy
 # — or its legacy MatmulPolicy subclass — additionally selects the
@@ -246,3 +251,133 @@ def abstract_cache(cfg: ModelConfig, batch: int, s_ctx: int,
     """ShapeDtypeStruct pytree of a full-capacity decode cache."""
     return jax.eval_shape(
         lambda: api.init_cache(cfg, batch, s_ctx, dtype))
+
+
+# ------------------------------------------------------- served weights
+
+# Primitives that move or index their first operand's values and change
+# none of them, so rounding commutes with them: a read through one is
+# the same read of the rounded leaf.  Their other operands are indices.
+_LAYOUT = frozenset({"reshape", "squeeze", "transpose", "broadcast_in_dim",
+                     "slice", "dynamic_slice", "gather"})
+# Calls whose body takes the call's operands one for one.
+_CALLS = frozenset({"jit", "closed_call", "remat2", "custom_jvp_call",
+                    "custom_vjp_call", "shard_map"})
+_NONE: frozenset = frozenset()
+
+
+def _union(sets) -> frozenset:
+    return _NONE.union(*sets)
+
+
+def _reads(jaxpr, raw: list, seen: dict) -> list:
+    """Follow the inputs' unrounded values through one jaxpr.
+
+    ``raw[i]`` is the set of top-level inputs whose unrounded values
+    ``jaxpr.invars[i]`` carries.  A read that rounds such a value to
+    bf16 adds its inputs to ``seen["rounded"]``; any other read adds
+    them to ``seen["kept"]``.  Returns the sets that the outvars carry.
+    """
+    env = dict(zip(jaxpr.invars, raw))
+
+    def get(v):
+        return _NONE if isinstance(v, Literal) else env.get(v, _NONE)
+
+    for eqn in jaxpr.eqns:
+        ins = [get(v) for v in eqn.invars]
+        if not any(ins):
+            continue
+        name = eqn.primitive.name
+        if name == "convert_element_type":
+            new = jnp.dtype(eqn.params["new_dtype"])
+            if new == jnp.bfloat16:
+                seen["rounded"] |= ins[0]
+                continue
+            if new != eqn.invars[0].aval.dtype:
+                seen["kept"] |= ins[0]
+                continue
+            outs = ins
+        elif name in _LAYOUT:
+            seen["kept"] |= _union(ins[1:])
+            outs = ins[:1]
+        else:
+            outs = _call_reads(eqn, ins, seen)
+            if outs is None:
+                seen["kept"] |= _union(ins)
+                continue
+        env.update(zip(eqn.outvars, outs))
+    return [get(v) for v in jaxpr.outvars]
+
+
+def _call_reads(eqn, ins: list, seen: dict) -> list | None:
+    """``_reads`` into the bodies of a call or a loop; None for any
+    other primitive.  A value carried round a loop is kept: the loop may
+    change it."""
+    p, name = eqn.params, eqn.primitive.name
+    if name == "scan":
+        nc, nk = p["num_consts"], p["num_carry"]
+        seen["kept"] |= _union(ins[nc:nc + nk])
+        outs = _reads(p["jaxpr"].jaxpr,
+                      ins[:nc] + [_NONE] * nk + ins[nc + nk:], seen)
+        seen["kept"] |= _union(outs[:nk])
+        return [_NONE] * nk + outs[nk:]
+    if name == "while":
+        cn, bn = p["cond_nconsts"], p["body_nconsts"]
+        carry = [_NONE] * (len(ins) - cn - bn)
+        seen["kept"] |= _union(ins[cn + bn:])
+        _reads(p["cond_jaxpr"].jaxpr, ins[:cn] + carry, seen)
+        seen["kept"] |= _union(
+            _reads(p["body_jaxpr"].jaxpr, ins[cn:cn + bn] + carry, seen))
+        return [_NONE] * len(eqn.outvars)
+    if name == "cond":
+        seen["kept"] |= ins[0]
+        per = [_reads(b.jaxpr, ins[1:], seen) for b in p["branches"]]
+        return [_union(o) for o in zip(*per)]
+    body = p.get("jaxpr", p.get("call_jaxpr"))
+    if name not in _CALLS or body is None:
+        return None
+    if isinstance(body, ClosedJaxpr):
+        body = body.jaxpr
+    return _reads(body, ins, seen)
+
+
+def bf16_read_leaves(params, programs) -> Any:
+    """Which float32 leaves of ``params`` every read rounds to bf16.
+
+    ``programs`` is a sequence of ``(fn, args)``, each called as
+    ``fn(params, *args)``.  A leaf qualifies when each of its reads in
+    every program, followed through layout-only ops and into nested
+    calls and loops, is a cast to bf16, and at least one read is.
+    Returns a tree of bools shaped like ``params``.
+    """
+    leaves, treedef = jax.tree.flatten(params)
+    rounded, kept = set(), set()
+    for fn, args in programs:
+        closed = jax.make_jaxpr(fn)(params, *args)
+        seen = {"rounded": set(), "kept": set()}
+        outs = _reads(closed.jaxpr, [frozenset({i}) for i in
+                                     range(len(closed.jaxpr.invars))],
+                      seen)
+        rounded |= seen["rounded"]
+        kept |= seen["kept"] | _union(outs)
+    return treedef.unflatten([
+        i in rounded and i not in kept and x.dtype == jnp.float32
+        for i, x in enumerate(leaves)])
+
+
+def round_leaves(params, which) -> Any:
+    """``params`` with each leaf that ``which`` marks cast to bf16 once.
+
+    The other leaves are the caller's own arrays.  A rounded leaf keeps
+    its leaf's sharding, so a mesh places it where it placed the leaf.
+    """
+    leaves, treedef = jax.tree.flatten(params)
+    picked = [x for x, w in zip(leaves, jax.tree.leaves(which)) if w]
+    if not picked:
+        return params
+    # an elementwise program: each output takes its input's sharding,
+    # and is committed to its devices where the input was
+    done = iter(jax.jit(lambda xs: [x.astype(jnp.bfloat16) for x in xs])(
+        picked))
+    return treedef.unflatten([next(done) if w else x for x, w in
+                              zip(leaves, jax.tree.leaves(which))])
